@@ -459,9 +459,16 @@ class GlobalMomentumFusion(Fusion):
 
     def fused_compress(self, cfg, u, v, m, ctx: StageCtx):
         """Alternate implementation of score+mask+extract through the fused
-        Pallas kernel (``kernels/gmf_compress.py``): per-leaf scalar norms +
+        Pallas kernels (``kernels/gmf_compress.py``): per-leaf scalar norms +
         threshold are computed outside, then one VMEM pass produces
         (G, U', V', mask). Returns (g, u, v, m, masks).
+
+        Under the exact selector the mask is set from the top-k's own
+        indices, exactly k per leaf, and the kernel only applies it. A
+        score recomputed elsewhere (inside the kernel, or in another XLA
+        fusion) can round the k-th element itself below the threshold and
+        send fewer than k entries. The sampled selector scores inside the
+        kernel (one pass).
 
         Numerically equivalent to ``scores``+topk+``extract`` up to
         reciprocal-vs-division rounding in the normalisation (boundary ties
@@ -481,14 +488,16 @@ class GlobalMomentumFusion(Fusion):
             inv_nm = 1.0 / (jnp.sqrt(jnp.sum(jnp.square(mf))) + cfg.eps)
             if cfg.selector == "exact":
                 z = jnp.abs((1.0 - tau) * vf * inv_nv + tau * mf * inv_nm)
-                thr = sparsify.exact_threshold(
+                _, idx = jax.lax.top_k(
                     z.reshape(-1), sparsify.num_keep(v_.size, cfg.rate))
-            else:
-                vs = sparsify.strided_sample_nd(vf)
-                ms = sparsify.strided_sample_nd(mf)
-                zs = jnp.abs((1.0 - tau) * vs * inv_nv + tau * ms * inv_nm)
-                k = sparsify.num_keep(zs.shape[0], cfg.rate)
-                thr = sparsify.exact_threshold(zs, k)
+                mask = jnp.zeros(v_.size, v_.dtype).at[idx].set(
+                    1, unique_indices=True).reshape(v_.shape)
+                return (*kops.apply_mask_update(u_, v_, mask), mask)
+            vs = sparsify.strided_sample_nd(vf)
+            ms = sparsify.strided_sample_nd(mf)
+            zs = jnp.abs((1.0 - tau) * vs * inv_nv + tau * ms * inv_nm)
+            k = sparsify.num_keep(zs.shape[0], cfg.rate)
+            thr = sparsify.exact_threshold(zs, k)
             return kops.gmf_compress(
                 u_, v_, m_, inv_norm_v=inv_nv, inv_norm_m=inv_nm, tau=tau,
                 threshold=thr)

@@ -226,8 +226,8 @@ def make_train_step(cfg, tcfg, ccfg, mesh=None):
     ``core.accounting.CostModel``)."""
     sync = tcfg.grad_sync
     # Compressed sync vmaps the loss over sync shards; moe_ep's shard_map
-    # under that vmap is untested on jax 0.4.x (ROADMAP), so EP is only
-    # enabled for the dense all-reduce path — gmf_* runs dense experts.
+    # under that vmap has no test yet (ROADMAP R3), so EP is only enabled
+    # for the dense all-reduce path — gmf_* runs dense experts.
     loss_fn = make_loss_fn(cfg, mesh=mesh if sync == "dense" else None)
 
     def _apply(params, opt, update, step):

@@ -70,7 +70,6 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import (
@@ -267,13 +266,13 @@ class ShardMapEngine(RoundEngine):
             return g_sum, new_states, infos.upload_nnz
 
         n_extras = int(thread_ids) + int(adaptive) + int(use_levels)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(P(), P("clients"), P("clients"), P(), P(), P(),
                       *([P("clients")] * n_extras)),
             out_specs=(P(), P("clients"), P("clients")),
-            check_rep=False,
+            check_vma=False,
         )
 
         def round_fn(params, cstates, sstate, gbar_prev, client_idx, batches,
@@ -389,12 +388,12 @@ class TopologyEngine(RoundEngine):
         pos_idx = [jnp.asarray(lay.position_indices(p)) for p in range(k1)]
 
         if self.leaf_backend == "shard":
-            grads_fn = shard_map(
+            grads_fn = jax.shard_map(
                 lambda params, batches: self._grads(params, batches),
                 mesh=self.mesh,
                 in_specs=(P(), P("clients")),
                 out_specs=P("clients"),
-                check_rep=False,
+                check_vma=False,
             )
         else:
             grads_fn = self._grads
@@ -455,13 +454,13 @@ class TopologyEngine(RoundEngine):
                     client_ids=ids)
                 return G, new_states, infos.upload_nnz
 
-            leaf_fn = shard_map(
+            leaf_fn = jax.shard_map(
                 leaf_body,
                 mesh=self.mesh,
                 in_specs=(P(), P("clients"), P("clients"), P(), P(), P(),
                           *([P("clients")] * int(thread_ids))),
                 out_specs=(P("clients"), P("clients"), P("clients")),
-                check_rep=False,
+                check_vma=False,
             )
         else:
             def leaf_fn(params, states, batches, gbar_prev, round_idx,

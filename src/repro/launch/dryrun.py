@@ -50,7 +50,6 @@ from repro.dist import step as dstep
 from repro.launch.mesh import make_production_mesh
 from repro.models import transformer
 from repro.utils import tree_map
-from repro.utils.compat import use_mesh
 
 # v5e hardware constants (roofline denominators).
 PEAK_FLOPS = 197e12         # bf16 FLOP/s per chip
@@ -146,7 +145,7 @@ def lower_one(arch_id: str, shape_name: str, *, multi_pod: bool, grad_sync: str,
         batch_sds = input_specs(cfg, shape, mode="train")
         b_shard = _shardings(mesh, shr.train_batch_specs(cfg, mesh))
         step_fn = dstep.make_train_step(cfg, tcfg, ccfg, mesh)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step_fn, in_shardings=(st_shard, b_shard), donate_argnums=(0,)
             ).lower(state_sds, batch_sds)
@@ -164,7 +163,7 @@ def lower_one(arch_id: str, shape_name: str, *, multi_pod: bool, grad_sync: str,
             lambda: transformer.init_cache(cfg, shape.global_batch, shape.seq_len)
         )
         c_shard = _shardings(mesh, shr.cache_specs_from(cache_sds, mesh))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step_fn, in_shardings=(p_shard, b_shard), out_shardings=(None, c_shard)
             ).lower(params_sds, batch_sds)
@@ -180,7 +179,7 @@ def lower_one(arch_id: str, shape_name: str, *, multi_pod: bool, grad_sync: str,
         )
         pos_sds = jax.ShapeDtypeStruct((), jnp.int32)
         step_fn = dstep.make_serve_step(cfg, mesh)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step_fn,
                 in_shardings=(p_shard, c_shard, tok_shard, None),
@@ -195,8 +194,6 @@ def lower_one(arch_id: str, shape_name: str, *, multi_pod: bool, grad_sync: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     coll = parse_collective_bytes(compiled.as_text())
     chips = mesh.devices.size
 
@@ -295,8 +292,6 @@ def lower_topology(arch_id: str, topology: str, *, clients: int = 8,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     coll = parse_collective_bytes(compiled.as_text())
     flops_per_chip = float(cost.get("flops", 0.0))
     bytes_per_chip = float(cost.get("bytes accessed", 0.0))

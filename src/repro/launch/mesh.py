@@ -11,25 +11,17 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    # jax >= 0.5 takes axis_types; 0.4.x (this container) does not.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """v5e pod meshes: (16, 16) = 256 chips single-pod; (2, 16, 16) = 512."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary test/CI mesh with Auto axis types."""
     shape, axes = tuple(shape), tuple(axes)
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, (jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_client_mesh(num_shards: int = 0):

@@ -35,6 +35,7 @@ import repro.obs as obs
 from repro.dist import step as dstep
 from repro.models import transformer
 from repro.serve import ServeConfig, ServeEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _prompt_batch(cfg, key_prompt, key_patch, b, prompt_len):
@@ -165,6 +166,7 @@ def run_engine(cfg, params, args) -> dict:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(configs.ARCH_IDS))
     ap.add_argument("--smoke", action="store_true")

@@ -59,6 +59,7 @@ from repro.launch.mesh import make_mesh
 from repro.models import transformer
 from repro.topo import TOPOLOGIES
 from repro.utils import tree_size
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def parse_stage_overrides(spec: str) -> dict:
@@ -224,6 +225,7 @@ def run_fl(args, ccfg, cfg):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(configs.ARCH_IDS))
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers
-from repro.utils.compat import shard_map_compat
 
 
 def init_moe(key, cfg, dtype=None):
@@ -264,12 +263,13 @@ def moe_ep(
                 aux = jax.lax.pmean(aux, inner_data)
             return y, aux
 
-        return shard_map_compat(
+        return jax.shard_map(
             body,
-            None if already_manual else mesh,
+            mesh=None if already_manual else mesh,
             in_specs=(w_specs, x_spec),
             out_specs=(x_spec, P()),
-            manual_axes=manual,
+            axis_names=manual,
+            check_vma=False,
         )(params, x)
 
     # replicated-token + psum-combine fallback (decode: T == 1)
@@ -284,10 +284,11 @@ def moe_ep(
             aux = jax.lax.pmean(aux, inner_data)
         return y, aux
 
-    return shard_map_compat(
+    return jax.shard_map(
         body,
-        None if already_manual else mesh,
+        mesh=None if already_manual else mesh,
         in_specs=(w_specs, x_spec, P(model_axis)),
         out_specs=(x_spec, P()),
-        manual_axes=manual,
+        axis_names=manual,
+        check_vma=False,
     )(params, x, ranks)

@@ -10,11 +10,13 @@ server_aggregate outputs for every preset x selector x wire dtype over a
 numbers + final params of the FetchSGD reference simulator on the shared
 tiny task).
 
-The schemes fixture was captured at the pre-refactor commit (PR 2 head) and
-the refactored registry compositions must reproduce it bit-exactly
-(tests/test_golden_schemes.py). Re-running this script against the
-refactored implementation must therefore be a no-op diff — that is the
-regression check.
+The schemes fixture pins the registry compositions of ``repro.core`` as
+they stand under JAX 0.9.0; the registry must reproduce it bit-exactly
+(tests/test_golden_schemes.py). It was first captured from the monolithic
+pre-registry implementation under JAX 0.4.37, whose registry port matched
+it bit for bit; it was re-captured when the toolchain moved to JAX 0.9.0,
+with the scheme code unchanged. Re-running this script must be a no-op
+diff — that is the regression check.
 
 The fetchsgd fixture was captured from ``repro.fl.fetchsgd``'s
 ``FetchSGDSimulator``, which was RETIRED in PR 3 (FetchSGD is now the
@@ -30,7 +32,6 @@ from __future__ import annotations
 import os
 import sys
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -66,18 +67,16 @@ VARIANTS = {
 
 
 def _params_and_grads():
+    """Inputs drawn with numpy, so they do not change with JAX's PRNG
+    implementation (``jax_threefry_partitionable`` flipped in JAX 0.5)."""
     params = {"w": jnp.zeros((64, 32)), "b": jnp.zeros((128,))}
-    key = jax.random.PRNGKey(1234)
-    grads = []
-    for t in range(ROUNDS):
-        per_client = []
-        for c in range(CLIENTS):
-            kc = jax.random.fold_in(jax.random.fold_in(key, t), c)
-            per_client.append({
-                "w": jax.random.normal(kc, (64, 32)),
-                "b": jax.random.normal(jax.random.fold_in(kc, 1), (128,)),
-            })
-        grads.append(per_client)
+    rng = np.random.default_rng(1234)
+    grads = [
+        [{"w": jnp.asarray(rng.standard_normal((64, 32), dtype=np.float32)),
+          "b": jnp.asarray(rng.standard_normal((128,), dtype=np.float32))}
+         for _ in range(CLIENTS)]
+        for _ in range(ROUNDS)
+    ]
     return params, grads
 
 

@@ -256,7 +256,6 @@ assert "--xla_force_host_platform_device_count=8" in os.environ["XLA_FLAGS"]
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis import jaxpr_audit
@@ -271,8 +270,8 @@ assert not drift, [f.format() for f in drift]
 # 2) gate demo: compile a REAL extra psum, splice its collectives into a
 #    pinned config's report, and the baseline check must reject it
 mesh = Mesh(np.array(jax.devices()), ("d",))
-extra_fn = jax.jit(shard_map(lambda x: jax.lax.psum(x, "d"),
-                             mesh=mesh, in_specs=P("d"), out_specs=P()))
+extra_fn = jax.jit(jax.shard_map(lambda x: jax.lax.psum(x, "d"),
+                                 mesh=mesh, in_specs=P("d"), out_specs=P()))
 hlo = extra_fn.lower(jnp.zeros((8, 4), jnp.float32)).compile().as_text()
 extra = jaxpr_audit.collective_counts(hlo)
 assert sum(extra.values()) >= 1, f"psum compiled to no collective: {extra!r}"

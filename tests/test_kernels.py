@@ -105,6 +105,37 @@ def test_kernels_inside_jit_and_grad_path():
     np.testing.assert_allclose(G_k["w"], G_r["w"], **TOL)
 
 
+def test_fused_exact_selector_sends_k_per_leaf():
+    """The fused path sends exactly k = ceil(rate·n) entries per leaf, as
+    the staged path does: its mask comes from the top-k's own indices, not
+    from a score recomputed against the threshold."""
+    from repro.core import CompressionConfig
+    from repro.core.registry import resolve
+    from repro.core.sparsify import num_keep
+    from repro.core.state import ClientState
+
+    rng = np.random.default_rng(0)
+    shapes = [(3, 3, 16, 16), (3, 3, 32, 32), (3, 3, 64, 64), (64, 10), (16,)]
+
+    def tree(scale=1.0):
+        return {f"l{i}": jnp.asarray(scale * rng.standard_normal(s), jnp.float32)
+                for i, s in enumerate(shapes)}
+
+    state = ClientState(u=tree(), v=tree(), m=tree())
+    grad, gbar = tree(), tree(0.1)
+    k = sum(num_keep(int(np.prod(s)), 0.1) for s in shapes)
+    for use_kernels in (False, True):
+        scheme = resolve(CompressionConfig(scheme="dgcwgmf", rate=0.1, tau=0.6,
+                                           use_kernels=use_kernels))
+        _, new, info = jax.jit(
+            lambda s, g, gb, scheme=scheme: scheme.client_compress(s, g, gb, 3)
+        )(state, grad, gbar)
+        assert int(info.upload_nnz) == k, use_kernels
+        for leaf, shape in zip(jax.tree.leaves(new.v), shapes, strict=True):
+            assert int(np.sum(np.asarray(leaf) == 0)) == num_keep(
+                int(np.prod(shape)), 0.1)
+
+
 def test_padding_never_selected():
     """Padded lanes (v=m=0 ⇒ z=0) must not enter the mask for thr>0."""
     n = 100  # heavily padded up to 65536

@@ -245,20 +245,25 @@ def test_fetchsgd_matches_retired_simulator_golden():
     ``FetchSGDSimulator``'s ledger numbers EXACTLY (sketch upload bytes,
     k-sparse download bytes, per-round totals) and its accuracy/params to
     float tolerance, on the same task/seed
-    (tests/golden/fetchsgd_golden.npz, captured pre-refactor)."""
+    (tests/golden/fetchsgd_golden.npz, captured pre-refactor).
+
+    The fixture was captured under JAX 0.4.37, whose threefry PRNG was not
+    yet partitionable (the default since JAX 0.5); the run reproduces those
+    draws — initial weights and sketch hashes — with that setting."""
     from tiny_task import GoldenTask
 
     from repro.fl import FLConfig, FLSimulator
 
     golden = np.load(os.path.join(
         os.path.dirname(__file__), "golden", "fetchsgd_golden.npz"))
-    task = GoldenTask(seed=0)
     fl = FLConfig(num_clients=4, rounds=6, batch_size=12, learning_rate=0.1,
                   eval_every=2, seed=0)
     comp = CompressionConfig(scheme="fetchsgd", sketch_rows=3, sketch_cols=128,
                              sketch_k_frac=0.05, sketch_momentum=0.9)
-    sim = FLSimulator(fl, comp, task.init_fn, task.loss_fn, task.eval_fn)
-    sim.run(task.batch_provider())
+    with jax.threefry_partitionable(False):
+        task = GoldenTask(seed=0)
+        sim = FLSimulator(fl, comp, task.init_fn, task.loss_fn, task.eval_fn)
+        sim.run(task.batch_provider())
 
     assert sim.ledger.upload_bytes == float(golden["upload_bytes"])
     assert sim.ledger.download_bytes == float(golden["download_bytes"])
