@@ -202,6 +202,12 @@ def effective_tau(cfg, round_idx) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+# The top-k's own scope, inside ``round.client_compress`` (fl/engine.py), so
+# the selection's device time can be told apart from momentum, fusion and
+# error feedback; also wraps the exact top-k of the fused kernel path.
+SELECT_SCOPE = "compress.select"
+
+
 class Selector:
     """Chooses the transmitted coordinate set.
 
@@ -232,20 +238,21 @@ class TopKSelector(Selector):
                    "or global via cfg.per_tensor")
 
     def select(self, cfg, scores, round_idx, rate=None):
-        if rate is not None:
+        with jax.named_scope(SELECT_SCOPE):
+            if rate is not None:
+                if cfg.per_tensor:
+                    return tree_map(
+                        lambda z: sparsify.topk_mask_dynamic(z, rate, cfg.selector),
+                        scores)
+                leaves, treedef = jax.tree_util.tree_flatten(scores)
+                masks = sparsify.global_topk_masks_dynamic(leaves, rate)
+                return jax.tree_util.tree_unflatten(treedef, masks)
             if cfg.per_tensor:
                 return tree_map(
-                    lambda z: sparsify.topk_mask_dynamic(z, rate, cfg.selector),
-                    scores)
+                    lambda z: sparsify.topk_mask(z, cfg.rate, cfg.selector), scores)
             leaves, treedef = jax.tree_util.tree_flatten(scores)
-            masks = sparsify.global_topk_masks_dynamic(leaves, rate)
+            masks = sparsify.global_topk_masks(leaves, cfg.rate)
             return jax.tree_util.tree_unflatten(treedef, masks)
-        if cfg.per_tensor:
-            return tree_map(
-                lambda z: sparsify.topk_mask(z, cfg.rate, cfg.selector), scores)
-        leaves, treedef = jax.tree_util.tree_flatten(scores)
-        masks = sparsify.global_topk_masks(leaves, cfg.rate)
-        return jax.tree_util.tree_unflatten(treedef, masks)
 
 
 @register("selector", "dense")
@@ -488,16 +495,18 @@ class GlobalMomentumFusion(Fusion):
             inv_nm = 1.0 / (jnp.sqrt(jnp.sum(jnp.square(mf))) + cfg.eps)
             if cfg.selector == "exact":
                 z = jnp.abs((1.0 - tau) * vf * inv_nv + tau * mf * inv_nm)
-                _, idx = jax.lax.top_k(
-                    z.reshape(-1), sparsify.num_keep(v_.size, cfg.rate))
-                mask = jnp.zeros(v_.size, v_.dtype).at[idx].set(
-                    1, unique_indices=True).reshape(v_.shape)
+                with jax.named_scope(SELECT_SCOPE):
+                    _, idx = jax.lax.top_k(
+                        z.reshape(-1), sparsify.num_keep(v_.size, cfg.rate))
+                    mask = jnp.zeros(v_.size, v_.dtype).at[idx].set(
+                        1, unique_indices=True).reshape(v_.shape)
                 return (*kops.apply_mask_update(u_, v_, mask), mask)
             vs = sparsify.strided_sample_nd(vf)
             ms = sparsify.strided_sample_nd(mf)
             zs = jnp.abs((1.0 - tau) * vs * inv_nv + tau * ms * inv_nm)
             k = sparsify.num_keep(zs.shape[0], cfg.rate)
-            thr = sparsify.exact_threshold(zs, k)
+            with jax.named_scope(SELECT_SCOPE):
+                thr = sparsify.exact_threshold(zs, k)
             return kops.gmf_compress(
                 u_, v_, m_, inv_norm_v=inv_nv, inv_norm_m=inv_nm, tau=tau,
                 threshold=thr)

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 from typing import Any, NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.utils import tree_map, tree_zeros_like
@@ -50,8 +51,12 @@ def init_server_state(params, *, use_momentum: bool,
 # Client-axis layout helpers shared by the round engines (fl/engine.py).
 # All three treat the leading axis of every leaf as the client axis, so the
 # same code serves the vmap path (device-local stack) and the shard_map path
-# (stack laid out over the ``clients`` mesh axis).
+# (stack laid out over the ``clients`` mesh axis). Gather and scatter run
+# under one ``named_scope``, so every engine's copies of the U/V/M stack are
+# named in XLA profiles.
 # ---------------------------------------------------------------------------
+
+CLIENT_STATE_SCOPE = "round.client_state"
 
 
 def stack_client_states(state: ClientState, num_clients: int) -> ClientState:
@@ -63,14 +68,16 @@ def stack_client_states(state: ClientState, num_clients: int) -> ClientState:
 
 def gather_client_states(cstates: ClientState, client_idx) -> ClientState:
     """Select the sampled clients' rows ([K, ...] -> [k, ...])."""
-    return tree_map(lambda x: jnp.take(x, client_idx, axis=0), cstates)
+    with jax.named_scope(CLIENT_STATE_SCOPE):
+        return tree_map(lambda x: jnp.take(x, client_idx, axis=0), cstates)
 
 
 def scatter_client_states(cstates: ClientState, client_idx, updated: ClientState) -> ClientState:
     """Write the sampled clients' updated rows back into the full stack."""
-    return tree_map(
-        lambda full, upd: full.at[client_idx].set(upd), cstates, updated
-    )
+    with jax.named_scope(CLIENT_STATE_SCOPE):
+        return tree_map(
+            lambda full, upd: full.at[client_idx].set(upd), cstates, updated
+        )
 
 
 # ---------------------------------------------------------------------------

@@ -240,21 +240,30 @@ class FLSimulator:
                                   on_round=on_round)
         fl = self.fl
         obs = obs_metrics.get()
+        # One sibling span per host phase of a round, each with round=t.
+        # The counts' readback sits between fl.wait and fl.account, inside
+        # no span: the wait has already synced on them.
         for t in range(fl.rounds):
             t0 = time.perf_counter()
             up_before = self.ledger.upload_bytes
             down_before = self.ledger.download_bytes
-            ids = self._sample_ids(t)
-            batches = batch_provider(t, ids, self._rng)
-            lr = self._lr_at(t)
-            rate_args, rate_vb = (), None
-            if self.rate_adaptive:
-                # Synchronous rounds have no staleness: gap = 0.0, which is
-                # also what makes zero-delay async ticks bitwise-identical.
-                rates, levels = self._rate_inputs(ids, 0.0)
-                rate_args = (rates, levels)
-                rate_vb = self._rate_value_bytes(levels)
-            with trace.span("round"):
+            with trace.span("fl.inputs", round=t):
+                ids = self._sample_ids(t)
+                ids_d = jnp.asarray(ids)
+                t_d = jnp.asarray(t)
+                lr_d = jnp.asarray(self._lr_at(t), jnp.float32)
+                rates = levels = None
+                rate_args = ()
+                if self.rate_adaptive:
+                    # Synchronous rounds have no staleness: gap = 0.0, which
+                    # is also what makes zero-delay async ticks
+                    # bitwise-identical. (The controller draws from its own
+                    # generator, so it may run before the batches.)
+                    rates, levels = self._rate_inputs(ids, 0.0)
+                    rate_args = (rates, levels)
+            with trace.span("fl.batches", round=t):
+                batches = batch_provider(t, ids, self._rng)
+            with trace.span("fl.dispatch", round=t):
                 (
                     self.params,
                     self.cstates,
@@ -268,66 +277,79 @@ class FLSimulator:
                     self.cstates,
                     self.sstate,
                     self.gbar_prev,
-                    jnp.asarray(ids),
+                    ids_d,
                     batches,
-                    jnp.asarray(t),
-                    jnp.asarray(lr, jnp.float32),
+                    t_d,
+                    lr_d,
                     self.tau_ctl.tau,
                     *rate_args,
                 )
-                up_nnz = jax.block_until_ready(up_nnz)
+            with trace.span("fl.wait", round=t):
+                up_nnz, down_nnz, union_nnz = jax.block_until_ready(
+                    (up_nnz, down_nnz, union_nnz))
             wall_ms = (time.perf_counter() - t0) * 1e3
             up_host = np.asarray(up_nnz)
-            # Ledger charges the POST-downlink broadcast (what hits the
-            # wire); the adaptive-tau overlap stays defined on the
-            # PRE-downlink union so downlink compression cannot alias the
-            # mask-alignment signal the controller integrates.
-            self.ledger.record_round(
-                up_host, float(down_nnz), self.total_params, len(ids),
-                value_bytes=rate_vb,
-            )
-            if fl.adaptive_tau:
-                self.tau_ctl = adaptive.update(
-                    self.tau_ctl,
-                    float(np.mean(up_host)),
-                    float(union_nnz),
-                    target_overlap=fl.tau_target_overlap,
-                    eta=fl.tau_eta,
-                    tau_max=fl.tau_max,
-                )
-            rec = {"round": t, "comm_gb": self.ledger.total_gb,
-                   "tau": float(self.tau_ctl.tau)}
-            if self.rate_adaptive:
-                rec["rate_mean"] = float(np.asarray(rates).mean())
-            if self.eval_fn and (t % fl.eval_every == 0 or t == fl.rounds - 1):
-                rec["accuracy"] = float(self.eval_fn(self.params))
-            self.history.append(rec)
-            if obs.enabled:
-                extra = (self._rate_obs(obs, rates, levels)
-                         if self.rate_adaptive else None)
-                self._record_round_obs(obs, t, rec, wall_ms,
-                                       up_before, down_before,
-                                       float(np.mean(up_host)),
-                                       float(down_nnz), float(union_nnz),
-                                       extra=extra)
-            if log_every and t % log_every == 0:
-                acc = rec.get("accuracy")
-                acc_s = f" acc={acc:.4f}" if acc is not None else ""
-                print(f"[round {t:4d}] comm={self.ledger.total_gb:.4f} GB{acc_s}", flush=True)
+            down_host = float(down_nnz)
+            # each read is a device-to-host copy: the union only where used
+            union_host = (float(union_nnz) if fl.adaptive_tau or obs.enabled
+                          else None)
+            with trace.span("fl.account", round=t):
+                self._account_round(
+                    obs, t, len(ids), up_host, down_host, union_host, rates,
+                    levels, wall_ms, up_before, down_before, log_every)
             if on_round:
-                on_round(t, self)
+                with trace.span("fl.on_round", round=t):
+                    on_round(t, self)
         return self.history
+
+    def _account_round(self, obs, t, cohort, up_host, down_host, union_host,
+                       rates, levels, wall_ms, up_before, down_before, log_every):
+        """A synchronous round's host bookkeeping on its read-back counts:
+        the ledger, adaptive tau, history, evaluation, telemetry, log."""
+        fl = self.fl
+        # Ledger charges the POST-downlink broadcast (what hits the wire);
+        # the adaptive-tau overlap stays defined on the PRE-downlink union
+        # so downlink compression cannot alias the mask-alignment signal
+        # the controller integrates.
+        self.ledger.record_round(
+            up_host, down_host, self.total_params, cohort,
+            value_bytes=self._rate_value_bytes(levels))
+        if fl.adaptive_tau:
+            self.tau_ctl = adaptive.update(
+                self.tau_ctl,
+                float(np.mean(up_host)),
+                union_host,
+                target_overlap=fl.tau_target_overlap,
+                eta=fl.tau_eta,
+                tau_max=fl.tau_max,
+            )
+        rec = {"round": t, "comm_gb": self.ledger.total_gb,
+               "tau": float(self.tau_ctl.tau)}
+        if self.rate_adaptive:
+            rec["rate_mean"] = float(np.asarray(rates).mean())
+        if self.eval_fn and (t % fl.eval_every == 0 or t == fl.rounds - 1):
+            rec["accuracy"] = float(self.eval_fn(self.params))
+        self.history.append(rec)
+        if obs.enabled:
+            extra = (self._rate_obs(obs, rates, levels)
+                     if self.rate_adaptive else None)
+            self._record_round_obs(obs, t, rec, wall_ms, up_before, down_before,
+                                   float(np.mean(up_host)), down_host,
+                                   union_host, extra=extra)
+        if log_every and t % log_every == 0:
+            acc = rec.get("accuracy")
+            acc_s = f" acc={acc:.4f}" if acc is not None else ""
+            print(f"[round {t:4d}] comm={self.ledger.total_gb:.4f} GB{acc_s}", flush=True)
 
     def _record_round_obs(self, obs, t, rec, wall_ms, up_before, down_before,
                           up_nnz_mean, down_nnz, union_nnz, extra=None):
         """Telemetry for one completed round/tick: the ``round`` event
-        (wall-clock + this round's wire bytes), the ``fl.round_ms``
-        series, and the compensation-state health block (EF residual
+        (wall-clock + this round's wire bytes), the ``fl.tau`` gauge,
+        and the compensation-state health block (EF residual
         mass, momentum norms, achieved-vs-target compression, NaN/Inf
         anomaly check on the broadcast). Called only when telemetry is
         enabled — everything here reads already-materialised host values
         except the health norms, which are one jitted bundle."""
-        obs.observe("fl.round_ms", wall_ms)
         obs.gauge_set("fl.tau", rec["tau"])
         ev = {"round": t, "wall_ms": wall_ms,
               "upload_bytes": self.ledger.upload_bytes - up_before,

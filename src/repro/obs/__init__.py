@@ -7,10 +7,11 @@ One low-overhead spine for every signal the system produces (see
   histograms with labeled series; a shared **no-op recorder** until
   ``obs.configure()`` turns it on, so instrument points cost nothing in
   the default (disabled) state and never branch inside jitted code.
-* ``obs.trace`` — nestable host-side spans (``with span("round/flush")``)
-  that land in the ``trace.span_ms`` histogram and forward into
-  ``jax.profiler.TraceAnnotation``; ``annotate_scope`` names sections of
-  jitted code in XLA profiles at zero runtime cost.
+* ``obs.trace`` — nestable host-side spans (``with span("fl.dispatch",
+  round=t)``): always a ``jax.profiler.TraceAnnotation`` (ids as event
+  stats), and with telemetry on also a ``trace.span_ms`` observation
+  labeled by the span path; ``annotate_scope`` names sections of jitted
+  code in XLA profiles at zero runtime cost.
 * ``obs.events`` / ``obs.export`` — versioned JSONL event sink plus
   Prometheus-textfile and JSON-summary exporters.
 * ``obs.health`` — compensation-state monitors computed from the

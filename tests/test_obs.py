@@ -100,10 +100,8 @@ def test_disabled_recorder_is_shared_noop_object():
     obs.get().gauge_set("b", 2.0)
     obs.get().observe("c", 3.0)
     obs.get().event("round", round=0)
-    # disabled spans are one shared reentrant null context manager
-    s1, s2 = obs_trace.span("x"), obs_trace.span("y")
-    assert s1 is s2
-    with s1:
+    # a disabled span keeps no path stack
+    with obs_trace.span("x"):
         assert obs_trace.current_path() == ""
 
 
@@ -270,6 +268,63 @@ def test_spans_nest_and_record_path_labelled_durations():
     h = rec.registry.histogram("trace.span_ms")
     assert h.summary(span="round")["count"] == 1
     assert h.summary(span="round/aggregate")["count"] == 1
+
+
+def _profiled(tmp_path, body):
+    """Events of the host's threads in a CPU profile taken around ``body``:
+    name -> list of stats dicts."""
+    d = str(tmp_path / "trace")
+    jax.profiler.start_trace(d)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = [os.path.join(r, f) for r, _, fs in os.walk(d) for f in fs
+             if f.endswith(".xplane.pb")]
+    events = {}
+    for plane in jax.profiler.ProfileData.from_file(pb).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append(dict(e.stats))
+    return events
+
+
+def test_disabled_span_is_a_profiler_event_and_records_nothing(tmp_path, monkeypatch):
+    """With telemetry off a span is still a profiler annotation, named
+    bare, its ids as event stats; no recorder method runs."""
+    def never(*_, **__):
+        raise AssertionError("a disabled span recorded something")
+
+    for method in ("observe", "counter_add", "gauge_set", "event"):
+        monkeypatch.setattr(obs_metrics.NOOP, method, never)
+
+    def body():
+        for t in range(2):
+            with obs_trace.span("fl.dispatch", round=t):
+                assert obs_trace.current_path() == ""
+                jnp.ones(4).block_until_ready()
+
+    events = _profiled(tmp_path, body)
+    assert [s.get("round") for s in events["fl.dispatch"]] == [0, 1]
+    assert obs.get() is obs_metrics.NOOP
+
+
+def test_enabled_span_is_a_profiler_event_and_a_path_labelled_series(tmp_path):
+    """With telemetry on a span is the same profiler event and lands in
+    ``trace.span_ms`` under its path: the ids are stats, never labels."""
+    rec = obs.configure()
+
+    def body():
+        with obs_trace.span("fl.round", round=3):
+            with obs_trace.span("fl.dispatch", round=3):
+                assert obs_trace.current_path() == "fl.round/fl.dispatch"
+
+    events = _profiled(tmp_path, body)
+    assert [s.get("round") for s in events["fl.dispatch"]] == [3]
+    h = rec.registry.histogram("trace.span_ms")
+    assert h.summary(span="fl.round/fl.dispatch")["count"] == 1
+    assert h.summary(span="fl.round/fl.dispatch", round=3)["count"] == 0
 
 
 # ---------------------------------------------------------------------------
