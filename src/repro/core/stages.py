@@ -224,11 +224,23 @@ class Selector:
 
     def select(self, cfg, ref_tree, round_idx, rate=None):
         """``rate=None`` (the default) selects at the static ``cfg.rate``;
-        a traced per-client rate from the adaptive controller switches the
-        magnitude selectors to the dynamic-k path (full sort instead of
-        ``lax.top_k`` — see ``sparsify.num_keep_dynamic`` for the bitwise
-        relationship between the two)."""
+        a traced per-client rate from the adaptive controller gives the
+        magnitude selectors a traced k, which the same threshold search
+        takes (see ``sparsify.num_keep_dynamic`` for the bitwise
+        relationship between the two k)."""
         raise NotImplementedError
+
+
+def topk_tree(cfg, scores, rate):
+    """{0,1} mask tree of the magnitude top-k of ``scores`` at ``rate``
+    (static or traced): per tensor (one threshold search per group of
+    same-size leaves) or global, as ``cfg.per_tensor`` says."""
+    leaves, treedef = jax.tree_util.tree_flatten(scores)
+    if cfg.per_tensor:
+        masks = sparsify.topk_masks(leaves, rate, cfg.selector)
+    else:
+        masks = sparsify.global_topk_masks(leaves, rate)
+    return jax.tree_util.tree_unflatten(treedef, masks)
 
 
 @register("selector", "topk")
@@ -239,20 +251,7 @@ class TopKSelector(Selector):
 
     def select(self, cfg, scores, round_idx, rate=None):
         with jax.named_scope(SELECT_SCOPE):
-            if rate is not None:
-                if cfg.per_tensor:
-                    return tree_map(
-                        lambda z: sparsify.topk_mask_dynamic(z, rate, cfg.selector),
-                        scores)
-                leaves, treedef = jax.tree_util.tree_flatten(scores)
-                masks = sparsify.global_topk_masks_dynamic(leaves, rate)
-                return jax.tree_util.tree_unflatten(treedef, masks)
-            if cfg.per_tensor:
-                return tree_map(
-                    lambda z: sparsify.topk_mask(z, cfg.rate, cfg.selector), scores)
-            leaves, treedef = jax.tree_util.tree_flatten(scores)
-            masks = sparsify.global_topk_masks(leaves, cfg.rate)
-            return jax.tree_util.tree_unflatten(treedef, masks)
+            return topk_tree(cfg, scores, cfg.rate if rate is None else rate)
 
 
 @register("selector", "dense")
@@ -801,13 +800,7 @@ class TopKDownlink(Downlink):
         # residual accumulates everything the clients have not seen yet;
         # dropped entries survive to the next round's selection.
         r = tree_map(jnp.add, residual, bcast)
-        if cfg.per_tensor:
-            masks = tree_map(
-                lambda z: sparsify.topk_mask(z, cfg.downlink_rate, cfg.selector), r)
-        else:
-            leaves, treedef = jax.tree_util.tree_flatten(r)
-            masks = jax.tree_util.tree_unflatten(
-                treedef, sparsify.global_topk_masks(leaves, cfg.downlink_rate))
+        masks = topk_tree(cfg, r, cfg.downlink_rate)
         # Unlike the uplink's V, the accumulated broadcast is mostly EXACT
         # zeros while the union is sparse — a zero top-k threshold would
         # select everything (|0| >= 0), so zero entries never transmit.

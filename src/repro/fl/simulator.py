@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import CommLedger, CompressionConfig, init_states
-from repro.core import adaptive, stack_client_states
+from repro.core import adaptive, sparsify, stack_client_states
 from repro.fl import availability as _availability
 from repro.fl.engine import BACKENDS, make_engine
 from repro.obs import health as obs_health
@@ -232,6 +232,9 @@ class FLSimulator:
     def run(self, batch_provider, *, log_every: int = 0, on_round=None):
         """batch_provider(round, client_ids, rng) -> stacked batch pytree with
         leading axis len(client_ids)."""
+        obs = obs_metrics.get()
+        if obs.enabled:
+            self._select_gauges(obs)
         if self.engine.name == "async":
             return self._run_async(batch_provider, log_every=log_every,
                                    on_round=on_round)
@@ -239,7 +242,6 @@ class FLSimulator:
             return self._run_topo(batch_provider, log_every=log_every,
                                   on_round=on_round)
         fl = self.fl
-        obs = obs_metrics.get()
         # One sibling span per host phase of a round, each with round=t.
         # The counts' readback sits between fl.wait and fl.account, inside
         # no span: the wait has already synced on them.
@@ -301,6 +303,17 @@ class FLSimulator:
                 with trace.span("fl.on_round", round=t):
                     on_round(t, self)
         return self.history
+
+    def _select_gauges(self, obs):
+        """How far the per-tensor top-k's grouping engages: one threshold
+        search per leaf size (``fl.select_groups``) over ``fl.select_leaves``
+        leaves. Set only where the scheme selects that way."""
+        if self.engine.scheme.selector.name != "topk" or not self.comp.per_tensor:
+            return
+        plan = sparsify.select_groups(
+            [x.shape for x in jax.tree_util.tree_leaves(self.params)])
+        obs.gauge_set("fl.select_groups", len(plan))
+        obs.gauge_set("fl.select_leaves", sum(map(len, plan)))
 
     def _account_round(self, obs, t, cohort, up_host, down_host, union_host,
                        rates, levels, wall_ms, up_before, down_before, log_every):

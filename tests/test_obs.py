@@ -253,6 +253,20 @@ def test_ledger_publishes_comm_series_when_enabled(tmp_path):
     assert reg.counter("comm.rounds").value() == float(sim.ledger.rounds)
 
 
+@pytest.mark.parametrize("scheme,gauges", [
+    ("dgcwgmf", {"fl.select_groups": 2.0, "fl.select_leaves": 2.0}),
+    ("fetchsgd", {}),
+])
+def test_select_plan_gauges_when_enabled(tmp_path, scheme, gauges):
+    """The per-tensor top-k's plan (one threshold search per leaf size) is
+    published once, where the scheme selects that way; the tiny task's two
+    leaves differ in size."""
+    reg = obs.configure(str(tmp_path)).registry
+    _run_sim(scheme=scheme, rounds=1)
+    got = {n: reg.gauge(n).value() for n in reg.names() if n.startswith("fl.select_")}
+    assert got == gauges
+
+
 # ---------------------------------------------------------------------------
 # Spans
 # ---------------------------------------------------------------------------
