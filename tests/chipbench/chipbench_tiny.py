@@ -39,17 +39,44 @@ TINY_LIMITS = {"bcast_gap": 1e-3, "delta_gap": 1e-3, "state_gap": 1e-3,
                "upload_gap": 1e-3, "download_gap": 1e-3}
 
 
+def read_spec(root: Path = REPO) -> dict:
+    return json.loads((root / "BENCHMARK.json").read_text())
+
+
+def per_layer_names(spec: dict, cell: str) -> set[str]:
+    """Names of the per-layer metrics that ``spec`` asks of ``cell``: the
+    entries whose ``workloads`` list it, and those without the key that move
+    an end-to-end metric the cell reports."""
+    reported = {m["name"] for m in spec["end_to_end"] if cell in m.get("workloads", [cell])}
+    return {m["name"] for m in spec["per_layer"]
+            if (cell in m["workloads"] if "workloads" in m else m["moves"] in reported)}
+
+
+def config_family(spec: dict, root: Path, config: str) -> str:
+    """The ``family`` that ``config``'s file under ``root`` names."""
+    file = next(c["file"] for c in spec["configs"] if c["name"] == config)
+    return json.loads((root / file).read_text())["family"]
+
+
+def family_per_layer(spec: dict, root: Path, family: str) -> set[str]:
+    """Per-layer names of every cell of ``spec`` whose configuration (its
+    file under ``root``) is of ``family``."""
+    return set().union(*(per_layer_names(spec, w["name"]) for w in spec["workloads"]
+                         if config_family(spec, root, w["config"]) == family))
+
+
 def tiny_root(tmp: Path, configs=None, traffic=None, cells=None, limits=None) -> Path:
     """``tmp`` laid out as a checkout: BENCHMARK.json with ``cells`` (name ->
     (config, traffic)) and the benchmark's files, plus the given new
-    configuration and traffic files."""
+    configuration and traffic files. A new cell takes the per-layer entries
+    of the accepted cells whose configuration is of its family."""
     configs = TINY_CONFIGS if configs is None else configs
     traffic = TINY_TRAFFIC if traffic is None else traffic
     cells = TINY_CELLS if cells is None else cells
     bench = tmp / "benchmarks" / "chip"
     shutil.copytree(REPO / "benchmarks" / "chip", bench,
                     ignore=shutil.ignore_patterns("__pycache__"))
-    spec = json.loads((REPO / "BENCHMARK.json").read_text())
+    accepted, spec = read_spec(), read_spec()
     for name, cfg in configs.items():
         (bench / "configs" / f"{name}.json").write_text(json.dumps(cfg))
         spec["configs"].append({"name": name, "source": "test", "reduced": [],
@@ -61,8 +88,11 @@ def tiny_root(tmp: Path, configs=None, traffic=None, cells=None, limits=None) ->
         (bench / "limits" / f"{name}.json").write_text(json.dumps(limits or TINY_LIMITS))
         spec["workloads"].append({"name": name, "config": cfg, "traffic": mix,
                                   "chips": 1, "why": "tiny"})
+        family = configs[cfg]["family"] if cfg in configs else config_family(accepted, REPO, cfg)
+        inherited = family_per_layer(accepted, REPO, family)
         for m in spec["per_layer"]:
-            m.setdefault("workloads", []).append(name)
+            if m["name"] in inherited and "workloads" in m:
+                m["workloads"].append(name)
     (tmp / "BENCHMARK.json").write_text(json.dumps(spec))
     return tmp
 

@@ -12,10 +12,11 @@ import jax.numpy as jnp
 import pytest
 
 BENCH = ct.REPO / "benchmarks" / "chip"
+CONFIG_FILES = {c["name"]: ct.REPO / c["file"] for c in ct.read_spec()["configs"]}
 
 
 def _config(name):
-    return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+    return json.loads(CONFIG_FILES[name].read_text())
 
 
 def _family(name):
@@ -57,8 +58,8 @@ def test_char_lstm_forward_flops_match_cost_analysis():
     assert 0.95 * xla <= ours <= xla, (ours, xla)
 
 
-@pytest.mark.parametrize("name,family", [("resnet56_cifar", "resnet_cifar"),
-                                         ("lstm_shakespeare", "char_lstm")])
+@pytest.mark.parametrize("name,family", [(name, _config(name)["family"])
+                                         for name in CONFIG_FILES])
 def test_configuration_states_its_parameter_count(name, family):
     cfg = _config(name)
     init_fn, _ = _family(family).program(cfg)

@@ -13,14 +13,13 @@ import sys
 import chipbench_tiny as ct
 import numpy as np
 import pytest
+from chipbench_token_mlp import token_mlp_root
 
 from benchmarks.chip import harness
 
 REPO = ct.REPO
 LSTM = "lstm_tiny-dgcwgmf-3of6"
 E2E = {"round_ms", "round_ms_p90", "wire_mb_per_round", "setup_s"}
-LAYER = {"batch_build_ms", "device_idle_share", "client_grads_ms", "client_compress_ms",
-         "server_update_ms", "round_mfu"}
 
 
 def _command(cwd, *extra):
@@ -59,7 +58,7 @@ def test_tiny_run_is_correct_and_reports_the_cells_metrics(root, trace):
     r = ct.run_cell(root, LSTM, seed=2147483703, seconds=0.5, trace=trace)
     assert r["correct"] is True, r["checks"]
     assert r["attempted"] >= 1 and r["failed"] == 0
-    assert set(r["metrics"]) == (LAYER if trace else E2E)
+    assert set(r["metrics"]) == (ct.per_layer_names(ct.read_spec(root), LSTM) if trace else E2E)
     assert all(np.isfinite(m["value"]) for m in r["metrics"].values())
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(r["device"])
     if trace:
@@ -82,11 +81,14 @@ def test_wire_is_counted_over_the_same_rounds_whatever_the_window(root):
     assert short["metrics"]["wire_mb_per_round"] == long["metrics"]["wire_mb_per_round"]
 
 
-def test_a_new_cell_needs_only_files_and_an_entry(tmp_path):
+@pytest.mark.parametrize("make_root", [ct.tiny_root, token_mlp_root],
+                         ids=["alone", "beside_a_new_family"])
+def test_a_new_cell_needs_only_files_and_an_entry(tmp_path, make_root):
     """``resnet56_cifar-none-20x64`` (scheme ``none``, no compression) from
     the configuration's entry, a traffic file, a limits file and a
     BENCHMARK.json entry; and the same kind of cell at a tiny size run
-    through the harness."""
+    through the harness. The same holds in a checkout that also holds a
+    third family with a metric of its own."""
     none_20x64 = json.loads(
         (REPO / "benchmarks/chip/traffic/dgcwgmf-20x64.json").read_text())
     none_20x64.update(scheme="none")
@@ -94,7 +96,7 @@ def test_a_new_cell_needs_only_files_and_an_entry(tmp_path):
         none_20x64.pop(k)
     tiny_none = dict(ct.TINY_TRAFFIC["dgcwgmf-4x8"], scheme="none")
     resnet56 = json.loads((REPO / "benchmarks/chip/configs/resnet56_cifar.json").read_text())
-    root = ct.tiny_root(
+    root = make_root(
         tmp_path, configs={"resnet56_cifar": resnet56,
                            "resnet8_tiny": ct.TINY_CONFIGS["resnet8_tiny"]},
         traffic={"none-20x64": none_20x64, "none-4x8": tiny_none},
@@ -103,7 +105,8 @@ def test_a_new_cell_needs_only_files_and_an_entry(tmp_path):
         limits={k: v for k, v in ct.TINY_LIMITS.items() if not k.startswith("state")})
     cell = harness.load_cell(root, "resnet56_cifar-none-20x64")
     assert cell.traffic["scheme"] == "none" and cell.config["depth"] == 56
-    assert {m["name"] for m in cell.per_layer} == LAYER
+    assert {m["name"] for m in cell.per_layer} == ct.family_per_layer(ct.read_spec(), REPO,
+                                                                      "resnet_cifar")
     r = ct.run_cell(root, "resnet8_tiny-none-4x8", seed=7, seconds=0.3)
     assert r["correct"] is True, r["checks"]
     assert "state_gap" not in r["checks"]           # no compression state
